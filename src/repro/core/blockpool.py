@@ -52,7 +52,14 @@ class PoolSaturated(RuntimeError):
     """Admission cannot be covered RIGHT NOW but in-flight rows will free
     blocks as they finish — a transient, not a permanent reject.  The
     scheduler keeps the request queued and retries on a later step;
-    ``ValueError`` stays the permanent "can never fit" reject."""
+    ``AdmissionRejected`` is the permanent "can never fit" reject."""
+
+
+class AdmissionRejected(ValueError):
+    """The request can never be served: it needs more positions than a
+    pool row holds, or more blocks than the whole pool can ever offer.
+    The scheduler records it as ERRORED and serves the rest of the
+    queue; any other error raised during admission propagates."""
 
 
 class BlockAllocator:

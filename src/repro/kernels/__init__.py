@@ -2,6 +2,7 @@
 
 Each kernel module pairs with a pure-jnp oracle in ``ref.py``; ``ops.py``
 exposes the jit'd wrappers the model layer dispatches to via
-``Runtime.use_pallas``.  On this CPU container kernels execute with
-``interpret=True``; on TPU the same ``pallas_call``s compile via Mosaic.
+``Runtime.use_pallas``.  On the CPU backend kernels run in interpret
+mode; on a TPU the same ``pallas_call``s compile via Mosaic
+(``ops.interpret_mode``).
 """

@@ -48,6 +48,36 @@ def check_shard_view(H: int, Hkv: int) -> None:
             "unsharded one produces exactly this mismatch")
 
 
+def split_history(is_hist, kp, w):
+    """Validity of key positions ``kp`` on either side of the
+    history/chunk boundary ``w``: history tiles hold positions below it,
+    chunk tiles positions at or above it.  Written with ``&``/``|``
+    because Mosaic cannot lower a select between two boolean vectors."""
+    return (is_hist & (kp < w)) | (~is_hist & (kp >= w))
+
+
+def scale_layout(scale):
+    """Pool scales (NB, bs, Hkv) -> kernel layout (NB, Hkv, bs).  One pool
+    block's scales for every head then form a (Hkv, bs) tile whose two
+    minor dims are whole array dims — a legal TPU block with ``bs`` on
+    lanes.  A per-head (1, bs) block would be neither 8-aligned nor the
+    whole head axis, which the TPU lowering refuses."""
+    return scale.transpose(0, 2, 1)
+
+
+def scale_column(ref, h, bs):
+    """The (bs, 1) scale column of kv head ``h`` from a (1, Hkv, bs)
+    ``scale_layout`` tile.  Both steps are masked sums in which every
+    other term is exactly 0, so the values are bit-exact copies; they
+    avoid a dynamic sublane slice and a lane-to-sublane transpose."""
+    s = ref[0].astype(jnp.float32)                    # (Hkv, bs)
+    head = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    row = jnp.sum(jnp.where(head == h, s, 0.0), axis=0, keepdims=True)
+    r = jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 0)
+    c = jax.lax.broadcasted_iota(jnp.int32, (bs, bs), 1)
+    return jnp.sum(jnp.where(r == c, row, 0.0), axis=1, keepdims=True)
+
+
 def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, sp_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, scale, window, bk, nk):
     ki = pl.program_id(1)
@@ -276,18 +306,19 @@ def _paged_decode_kernel_quant(tbl_ref, pos_ref, q_ref, k_ref, v_ref,
     blocks are instead read from its fp ring tail (ring slot ti % rtail),
     so quantization error never sits where attention mass is largest."""
     ti = pl.program_id(1)
+    h = pl.program_id(0) % hkv
     k8 = k_ref[0, 0].astype(jnp.float32)              # (bs, d) int8 tile
     v8 = v_ref[0, 0].astype(jnp.float32)
-    ks = ks_ref[0, 0].astype(jnp.float32)             # (bs,) f32 scales
-    vs = vs_ref[0, 0].astype(jnp.float32)
+    ks = scale_column(ks_ref, h, bs)                  # (bs, 1) f32 scales
+    vs = scale_column(vs_ref, h, bs)
     kt = kt_ref[0, 0].astype(jnp.float32)             # (bs, d) fp ring tile
     vt = vt_ref[0, 0].astype(jnp.float32)
     pos = pos_ref[pl.program_id(0) // hkv]            # this row's position
 
     open_b = pos // bs
     use_fp = (ti <= open_b) & (ti > open_b - rtail)   # scalar: recent block?
-    k = jnp.where(use_fp, kt, k8 * ks[:, None])
-    v = jnp.where(use_fp, vt, v8 * vs[:, None])
+    k = jnp.where(use_fp, kt, k8 * ks)
+    v = jnp.where(use_fp, vt, v8 * vs)
     _paged_accumulate(ti, nbt, q_ref, k, v, pos, o_ref, m_scr, l_scr,
                       acc_scr, scale=scale, bs=bs)
 
@@ -312,8 +343,8 @@ def paged_decode_attention_quant(q, k_pool, v_pool, k_scale, v_scale,
     qr = q.reshape(B, Hkv, G, D).reshape(B * Hkv, G, D)
     kr = k_pool.transpose(2, 0, 1, 3)                 # (Hkv, NB, bs, D) int8
     vr = v_pool.transpose(2, 0, 1, 3)
-    ksr = k_scale.transpose(2, 0, 1)                  # (Hkv, NB, bs) f32
-    vsr = v_scale.transpose(2, 0, 1)
+    ksr = scale_layout(k_scale)                       # (NB, Hkv, bs) f32
+    vsr = scale_layout(v_scale)
     ktr = (k_tail.reshape(B, R, bs, Hkv, D)           # (B*Hkv, R, bs, D)
            .transpose(0, 3, 1, 2, 4).reshape(B * Hkv, R, bs, D))
     vtr = (v_tail.reshape(B, R, bs, Hkv, D)
@@ -332,12 +363,12 @@ def paged_decode_attention_quant(q, k_pool, v_pool, k_scale, v_scale,
             pl.BlockSpec((1, 1, bs, D),
                          lambda bh, ti, tbl, pos, hkv=Hkv:
                          (bh % hkv, tbl[bh // hkv, ti], 0, 0)),
-            pl.BlockSpec((1, 1, bs),
+            pl.BlockSpec((1, Hkv, bs),
                          lambda bh, ti, tbl, pos, hkv=Hkv:
-                         (bh % hkv, tbl[bh // hkv, ti], 0)),
-            pl.BlockSpec((1, 1, bs),
+                         (tbl[bh // hkv, ti], 0, 0)),
+            pl.BlockSpec((1, Hkv, bs),
                          lambda bh, ti, tbl, pos, hkv=Hkv:
-                         (bh % hkv, tbl[bh // hkv, ti], 0)),
+                         (tbl[bh // hkv, ti], 0, 0)),
             pl.BlockSpec((1, 1, bs, D),
                          lambda bh, ti, tbl, pos, r=R: (bh, ti % r, 0, 0)),
             pl.BlockSpec((1, 1, bs, D),

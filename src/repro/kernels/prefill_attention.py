@@ -62,7 +62,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.decode_attention import check_shard_view
+from repro.kernels.decode_attention import (check_shard_view, scale_column,
+                                            scale_layout, split_history)
 
 NEG_INF = -1e30
 
@@ -106,8 +107,7 @@ def _tile_mask(ti, sc_ref, CG, bs, G, nbt):
     qp = c0 + jax.lax.broadcasted_iota(jnp.int32, (CG, bs), 0) // G
     is_hist = ti < nbt
     kp = jnp.where(is_hist, ti * bs + j, c0 + (ti - nbt) * bs + j)
-    ok = (kp <= qp) & jnp.where(is_hist, kp < w_eff, kp >= w_eff)
-    return ok
+    return (kp <= qp) & split_history(is_hist, kp, w_eff)
 
 
 def _paged_prefill_kernel(tbl_ref, sc_ref, q_ref, k_ref, v_ref, kc_ref,
@@ -214,11 +214,12 @@ def _paged_prefill_kernel_quant(tbl_ref, sc_ref, q_ref, k_ref, v_ref,
     so the ring still holds exactly those blocks.  Chunk tiles use the fp
     operands like the fp kernel."""
     ti = pl.program_id(1)
+    h = pl.program_id(0)
     q = q_ref[0].astype(jnp.float32)                  # (C*G, d)
     k8 = k_ref[0, 0].astype(jnp.float32)              # (bs, d) int8 tile
     v8 = v_ref[0, 0].astype(jnp.float32)
-    ks = ks_ref[0, 0].astype(jnp.float32)             # (bs,) f32 scales
-    vs = vs_ref[0, 0].astype(jnp.float32)
+    ks = scale_column(ks_ref, h, bs)                  # (bs, 1) f32 scales
+    vs = scale_column(vs_ref, h, bs)
     kt = kt_ref[0, 0].astype(jnp.float32)             # (bs, d) fp ring tile
     vt = vt_ref[0, 0].astype(jnp.float32)
     kc = kc_ref[0, 0].astype(jnp.float32)             # (bs, d) fp chunk tile
@@ -227,8 +228,8 @@ def _paged_prefill_kernel_quant(tbl_ref, sc_ref, q_ref, k_ref, v_ref,
     hb = (sc_ref[1] - 1) // bs                        # newest history block
     use_fp = (ti <= hb) & (ti > hb - rtail)           # scalar: ring block?
     is_hist = ti < nbt
-    k = jnp.where(is_hist, jnp.where(use_fp, kt, k8 * ks[:, None]), kc)
-    v = jnp.where(is_hist, jnp.where(use_fp, vt, v8 * vs[:, None]), vc)
+    k = jnp.where(is_hist, jnp.where(use_fp, kt, k8 * ks), kc)
+    v = jnp.where(is_hist, jnp.where(use_fp, vt, v8 * vs), vc)
     s = q @ k.T * scale
     s = jnp.where(_tile_mask(ti, sc_ref, q.shape[0], bs, G, nbt),
                   s, NEG_INF)
@@ -257,8 +258,8 @@ def paged_prefill_attention_quant(q, k_chunk, v_chunk, k_pool, v_pool,
     qr, kcr, vcr = _chunk_layouts(q, k_chunk, v_chunk, bs)
     kr = k_pool.transpose(2, 0, 1, 3)                 # (Hkv, NB, bs, D) int8
     vr = v_pool.transpose(2, 0, 1, 3)
-    ksr = k_scale.transpose(2, 0, 1)                  # (Hkv, NB, bs) f32
-    vsr = v_scale.transpose(2, 0, 1)
+    ksr = scale_layout(k_scale)                       # (NB, Hkv, bs) f32
+    vsr = scale_layout(v_scale)
     ktr = (k_tail_row.reshape(R, bs, Hkv, D)          # (Hkv, R, bs, D)
            .transpose(2, 0, 1, 3))
     vtr = (v_tail_row.reshape(R, bs, Hkv, D)
@@ -270,7 +271,7 @@ def paged_prefill_attention_quant(q, k_chunk, v_chunk, k_pool, v_pool,
         return (h, tbl[jnp.minimum(ti, n - 1)], 0, 0)
 
     def hist_ix_s(h, ti, tbl, sc, n=NBt):
-        return (h, tbl[jnp.minimum(ti, n - 1)], 0)
+        return (tbl[jnp.minimum(ti, n - 1)], 0, 0)
 
     def ring_ix(h, ti, tbl, sc, r=R):
         return (h, ti % r, 0, 0)
@@ -287,8 +288,8 @@ def paged_prefill_attention_quant(q, k_chunk, v_chunk, k_pool, v_pool,
             pl.BlockSpec((1, C * G, D), lambda h, ti, tbl, sc: (h, 0, 0)),
             pl.BlockSpec((1, 1, bs, D), hist_ix),
             pl.BlockSpec((1, 1, bs, D), hist_ix),
-            pl.BlockSpec((1, 1, bs), hist_ix_s),
-            pl.BlockSpec((1, 1, bs), hist_ix_s),
+            pl.BlockSpec((1, Hkv, bs), hist_ix_s),
+            pl.BlockSpec((1, Hkv, bs), hist_ix_s),
             pl.BlockSpec((1, 1, bs, D), ring_ix),
             pl.BlockSpec((1, 1, bs, D), ring_ix),
             pl.BlockSpec((1, 1, bs, D), chunk_ix),
@@ -330,8 +331,7 @@ def _packed_tile_mask(qt, ti, desc_ref, BG, bs, G, nbt):
     qp = c0 + (qt - qt0) * bs + r // G
     is_hist = ti < nbt
     kp = jnp.where(is_hist, ti * bs + j, c0 + (ti - nbt) * bs + j)
-    ok = (kp <= qp) & jnp.where(is_hist, kp < w_eff, kp >= w_eff)
-    return ok
+    return (kp <= qp) & split_history(is_hist, kp, w_eff)
 
 
 def _paged_prefill_packed_kernel(tbl_ref, desc_ref, q_ref, k_ref, v_ref,
@@ -432,13 +432,14 @@ def _paged_prefill_packed_kernel_quant(tbl_ref, desc_ref, q_ref, k_ref,
     history block hb, from its w_eff) come from that segment's fp ring
     tail — per-tile w_eff makes the recency gate per-segment, otherwise
     identical to the chunked quant kernel."""
+    h = pl.program_id(0)
     qt = pl.program_id(1)
     ti = pl.program_id(2)
     q = q_ref[0].astype(jnp.float32)                  # (bs*G, d)
     k8 = k_ref[0, 0].astype(jnp.float32)              # (bs, d) int8 tile
     v8 = v_ref[0, 0].astype(jnp.float32)
-    ks = ks_ref[0, 0].astype(jnp.float32)             # (bs,) f32 scales
-    vs = vs_ref[0, 0].astype(jnp.float32)
+    ks = scale_column(ks_ref, h, bs)                  # (bs, 1) f32 scales
+    vs = scale_column(vs_ref, h, bs)
     kt = kt_ref[0, 0, 0].astype(jnp.float32)          # (bs, d) fp ring tile
     vt = vt_ref[0, 0, 0].astype(jnp.float32)
     kc = kc_ref[0, 0].astype(jnp.float32)             # (bs, d) fp chunk tile
@@ -447,8 +448,8 @@ def _paged_prefill_packed_kernel_quant(tbl_ref, desc_ref, q_ref, k_ref,
     hb = (desc_ref[2, qt] - 1) // bs                  # seg's newest hist blk
     use_fp = (ti <= hb) & (ti > hb - rtail)
     is_hist = ti < nbt
-    k = jnp.where(is_hist, jnp.where(use_fp, kt, k8 * ks[:, None]), kc)
-    v = jnp.where(is_hist, jnp.where(use_fp, vt, v8 * vs[:, None]), vc)
+    k = jnp.where(is_hist, jnp.where(use_fp, kt, k8 * ks), kc)
+    v = jnp.where(is_hist, jnp.where(use_fp, vt, v8 * vs), vc)
     s = q @ k.T * scale
     s = jnp.where(_packed_tile_mask(qt, ti, desc_ref, q.shape[0], bs, G,
                                     nbt), s, NEG_INF)
@@ -481,8 +482,8 @@ def paged_prefill_attention_packed_quant(q, k_chunk, v_chunk, k_pool,
     qr, kcr, vcr = _chunk_layouts(q, k_chunk, v_chunk, bs)
     kr = k_pool.transpose(2, 0, 1, 3)                 # (Hkv, NB, bs, D) int8
     vr = v_pool.transpose(2, 0, 1, 3)
-    ksr = k_scale.transpose(2, 0, 1)                  # (Hkv, NB, bs) f32
-    vsr = v_scale.transpose(2, 0, 1)
+    ksr = scale_layout(k_scale)                       # (NB, Hkv, bs) f32
+    vsr = scale_layout(v_scale)
     ktr = (k_tails.reshape(S, R, bs, Hkv, D)          # (Hkv, S, R, bs, D)
            .transpose(3, 0, 1, 2, 4))
     vtr = (v_tails.reshape(S, R, bs, Hkv, D)
@@ -492,7 +493,7 @@ def paged_prefill_attention_packed_quant(q, k_chunk, v_chunk, k_pool,
         return (h, tbl[dsc[0, qt], jnp.minimum(ti, n - 1)], 0, 0)
 
     def hist_ix_s(h, qt, ti, tbl, dsc, n=NBt):
-        return (h, tbl[dsc[0, qt], jnp.minimum(ti, n - 1)], 0)
+        return (tbl[dsc[0, qt], jnp.minimum(ti, n - 1)], 0, 0)
 
     def ring_ix(h, qt, ti, tbl, dsc, r=R):
         return (h, dsc[0, qt], ti % r, 0, 0)
@@ -513,8 +514,8 @@ def paged_prefill_attention_packed_quant(q, k_chunk, v_chunk, k_pool,
             pl.BlockSpec((1, bs * G, D), q_ix),
             pl.BlockSpec((1, 1, bs, D), hist_ix),
             pl.BlockSpec((1, 1, bs, D), hist_ix),
-            pl.BlockSpec((1, 1, bs), hist_ix_s),
-            pl.BlockSpec((1, 1, bs), hist_ix_s),
+            pl.BlockSpec((1, Hkv, bs), hist_ix_s),
+            pl.BlockSpec((1, Hkv, bs), hist_ix_s),
             pl.BlockSpec((1, 1, 1, bs, D), ring_ix),
             pl.BlockSpec((1, 1, 1, bs, D), ring_ix),
             pl.BlockSpec((1, 1, bs, D), chunk_ix),

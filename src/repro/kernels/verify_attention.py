@@ -43,7 +43,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.decode_attention import check_shard_view
+from repro.kernels.decode_attention import (check_shard_view, scale_column,
+                                            scale_layout, split_history)
 
 NEG_INF = -1e30
 
@@ -88,7 +89,7 @@ def _verify_mask(ti, c0, CG, bs, G, nbt):
     qp = c0 + jax.lax.broadcasted_iota(jnp.int32, (CG, bs), 0) // G
     is_hist = ti < nbt
     kp = jnp.where(is_hist, ti * bs + j, c0 + (ti - nbt) * bs + j)
-    return (kp <= qp) & jnp.where(is_hist, kp < c0, kp >= c0)
+    return (kp <= qp) & split_history(is_hist, kp, c0)
 
 
 def _verify_kernel(tbl_ref, c0_ref, q_ref, k_ref, v_ref, kc_ref, vc_ref,
@@ -197,20 +198,20 @@ def _verify_kernel_quant(tbl_ref, c0_ref, q_ref, k_ref, v_ref, ks_ref,
     values are computed on both views and selected per (query, key);
     bundle tiles collapse to the fp operands on both views, so the
     select is a no-op there."""
-    b, ti = pl.program_id(0), pl.program_id(2)
+    b, h, ti = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     q = q_ref[0, 0].astype(jnp.float32)               # (Cv*G, d)
     k8 = k_ref[0, 0].astype(jnp.float32)              # (bs, d) int8 tile
     v8 = v_ref[0, 0].astype(jnp.float32)
-    ks = ks_ref[0, 0].astype(jnp.float32)             # (bs,) f32 scales
-    vs = vs_ref[0, 0].astype(jnp.float32)
+    ks = scale_column(ks_ref, h, bs)                  # (bs, 1) f32 scales
+    vs = scale_column(vs_ref, h, bs)
     kt = kt_ref[0, 0, 0].astype(jnp.float32)          # (bs, d) ring snapshot
     vt = vt_ref[0, 0, 0].astype(jnp.float32)
     kc = kc_ref[0, 0, 0].astype(jnp.float32)          # (bs, d) bundle tile
     vc = vc_ref[0, 0, 0].astype(jnp.float32)
 
     is_hist = ti < nbt
-    k_int = jnp.where(is_hist, k8 * ks[:, None], kc)  # int8 view of tile
-    v_int = jnp.where(is_hist, v8 * vs[:, None], vc)
+    k_int = jnp.where(is_hist, k8 * ks, kc)           # int8 view of tile
+    v_int = jnp.where(is_hist, v8 * vs, vc)
     k_fp = jnp.where(is_hist, kt, kc)                 # fp-ring view
     v_fp = jnp.where(is_hist, vt, vc)
 
@@ -250,8 +251,8 @@ def paged_verify_attention_quant(q, k_chunk, v_chunk, k_pool, v_pool,
     qr, kcr, vcr = _verify_layouts(q, k_chunk, v_chunk, bs)
     kr = k_pool.transpose(2, 0, 1, 3)                 # (Hkv, NB, bs, D) int8
     vr = v_pool.transpose(2, 0, 1, 3)
-    ksr = k_scale.transpose(2, 0, 1)                  # (Hkv, NB, bs) f32
-    vsr = v_scale.transpose(2, 0, 1)
+    ksr = scale_layout(k_scale)                       # (NB, Hkv, bs) f32
+    vsr = scale_layout(v_scale)
     ktr = (k_tails.reshape(B, R, bs, Hkv, D)          # (B, Hkv, R, bs, D)
            .transpose(0, 3, 1, 2, 4))
     vtr = (v_tails.reshape(B, R, bs, Hkv, D)
@@ -264,7 +265,7 @@ def paged_verify_attention_quant(q, k_chunk, v_chunk, k_pool, v_pool,
         return (h, tbl[b, jnp.minimum(ti, n - 1)], 0, 0)
 
     def hist_ix_s(b, h, ti, tbl, c0, n=NBt):
-        return (h, tbl[b, jnp.minimum(ti, n - 1)], 0)
+        return (tbl[b, jnp.minimum(ti, n - 1)], 0, 0)
 
     def ring_ix(b, h, ti, tbl, c0, r=R):
         return (b, h, ti % r, 0, 0)
@@ -281,8 +282,8 @@ def paged_verify_attention_quant(q, k_chunk, v_chunk, k_pool, v_pool,
             pl.BlockSpec((1, 1, Cv * G, D), q_ix),
             pl.BlockSpec((1, 1, bs, D), hist_ix),
             pl.BlockSpec((1, 1, bs, D), hist_ix),
-            pl.BlockSpec((1, 1, bs), hist_ix_s),
-            pl.BlockSpec((1, 1, bs), hist_ix_s),
+            pl.BlockSpec((1, Hkv, bs), hist_ix_s),
+            pl.BlockSpec((1, Hkv, bs), hist_ix_s),
             pl.BlockSpec((1, 1, 1, bs, D), ring_ix),
             pl.BlockSpec((1, 1, 1, bs, D), ring_ix),
             pl.BlockSpec((1, 1, 1, bs, D), chunk_ix),

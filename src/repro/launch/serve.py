@@ -29,6 +29,7 @@ from repro.core import HashEmbedder
 from repro.core.metrics import RunMetrics, summarize_runs
 from repro.data.pipeline import paper_prompt_sets
 from repro.models import init_params
+from repro.runtime import enable_compile_cache
 from repro.serving import Engine, PagedEngine
 from repro.serving.scheduler import (ContinuousBatchingScheduler,
                                      RequestOutcome)
@@ -99,12 +100,17 @@ class ShardedServer:
     compute, so replicas genuinely overlap."""
 
     def __init__(self, cfg, params, *, replicas: int = 1, tp: int = 1,
-                 meshes=None, use_pallas: bool = False, **engine_kw):
+                 meshes=None, use_pallas: Optional[bool] = None,
+                 **engine_kw):
+        from jax.sharding import NamedSharding, PartitionSpec
         from repro.launch.mesh import serving_meshes
+        from repro.runtime import on_tpu
         from repro.sharding import serving_runtime
 
         if meshes is None:
             meshes = serving_meshes(replicas, tp)
+        if use_pallas is None:
+            use_pallas = on_tpu()
         self.lock = threading.RLock()
         self._admitted_by: dict = {}
         self.shared_stats = {"cross_replica_promotions": 0,
@@ -117,7 +123,12 @@ class ShardedServer:
             kw = dict(engine_kw)
             if shared is not None:
                 kw["recycler"] = shared
-            eng = PagedEngine(cfg, params, rt=rt, **kw)
+            # each replica's weights live on its own devices, placed once:
+            # otherwise every dispatch would copy them from the device
+            # init_params left them on
+            local = jax.device_put(params,
+                                   NamedSharding(mesh, PartitionSpec()))
+            eng = PagedEngine(cfg, local, rt=rt, **kw)
             if shared is None:
                 shared = eng.recycler          # replica 0's becomes the L2
             eng.recycler = _SharedRecycler(shared, r, self.lock,
@@ -272,6 +283,7 @@ def main():
                          "(needs D*T devices; force host devices with "
                          "XLA_FLAGS=--xla_force_host_platform_device_count)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -1130,8 +1130,7 @@ def cross_kv(cfg: ModelConfig, p, enc_out):
 # ---------------------------------------------------------------------------
 def _pallas_self(cfg, q, k, v, positions, window, rt):
     from repro.kernels import ops
-    return ops.flash_attention(q, k, v, causal=True, window=window,
-                               interpret=rt.pallas_interpret)
+    return ops.flash_attention(q, k, v, causal=True, window=window)
 
 
 def _pallas_prefill(cfg, q, cache, positions, window, rt):
@@ -1143,15 +1142,13 @@ def _pallas_prefill(cfg, q, cache, positions, window, rt):
 def _pallas_decode(cfg, q, cache, positions, window, rt):
     from repro.kernels import ops
     return ops.decode_attention(q, cache["k"], cache["v"], cache["slot_pos"],
-                                positions[0], window=window,
-                                interpret=rt.pallas_interpret)
+                                positions[0], window=window)
 
 
 def _pallas_decode_batched(cfg, q, cache, pos, window, rt):
     from repro.kernels import ops
     return ops.decode_attention_batched(
-        q, cache["k"], cache["v"], cache["slot_pos"], pos, window=window,
-        interpret=rt.pallas_interpret)
+        q, cache["k"], cache["v"], cache["slot_pos"], pos, window=window)
 
 
 def _pallas_prefill_paged(cfg, q, k_chunk, v_chunk, cache, row, table_row,
@@ -1162,11 +1159,9 @@ def _pallas_prefill_paged(cfg, q, k_chunk, v_chunk, cache, row, table_row,
             q, k_chunk, v_chunk, cache["k"], cache["v"],
             cache["k_scale"], cache["v_scale"],
             cache["k_tail"][row], cache["v_tail"][row],
-            table_row, c0, w_eff,
-            interpret=rt.pallas_interpret)
+            table_row, c0, w_eff)
     return ops.paged_prefill_attention(
-        q, k_chunk, v_chunk, cache["k"], cache["v"], table_row, c0, w_eff,
-        interpret=rt.pallas_interpret)
+        q, k_chunk, v_chunk, cache["k"], cache["v"], table_row, c0, w_eff)
 
 
 def _pallas_prefill_packed(cfg, q, k_chunk, v_chunk, cache, rows, tables,
@@ -1185,10 +1180,9 @@ def _pallas_prefill_packed(cfg, q, k_chunk, v_chunk, cache, rows, tables,
             q, k_chunk, v_chunk, cache["k"], cache["v"],
             cache["k_scale"], cache["v_scale"],
             cache["k_tail"][rows], cache["v_tail"][rows],
-            tables, desc, interpret=rt.pallas_interpret)
+            tables, desc)
     return ops.paged_prefill_attention_packed(
-        q, k_chunk, v_chunk, cache["k"], cache["v"], tables, desc,
-        interpret=rt.pallas_interpret)
+        q, k_chunk, v_chunk, cache["k"], cache["v"], tables, desc)
 
 
 def _pallas_verify_paged(cfg, q, k_chunk, v_chunk, cache, c0s, rt):
@@ -1198,10 +1192,10 @@ def _pallas_verify_paged(cfg, q, k_chunk, v_chunk, cache, c0s, rt):
             q, k_chunk, v_chunk, cache["k"], cache["v"],
             cache["k_scale"], cache["v_scale"],
             cache["k_tail_snap"], cache["v_tail_snap"],
-            cache["block_tables"], c0s, interpret=rt.pallas_interpret)
+            cache["block_tables"], c0s)
     return ops.paged_verify_attention(
         q, k_chunk, v_chunk, cache["k"], cache["v"],
-        cache["block_tables"], c0s, interpret=rt.pallas_interpret)
+        cache["block_tables"], c0s)
 
 
 def _pallas_decode_paged(cfg, q, cache, pos, rt):
@@ -1209,11 +1203,9 @@ def _pallas_decode_paged(cfg, q, cache, pos, rt):
     if is_quant_cache(cache):
         return ops.paged_decode_attention_quant(
             q, cache["k"], cache["v"], cache["k_scale"], cache["v_scale"],
-            cache["k_tail"], cache["v_tail"], cache["block_tables"], pos,
-            interpret=rt.pallas_interpret)
+            cache["k_tail"], cache["v_tail"], cache["block_tables"], pos)
     return ops.paged_decode_attention(
-        q, cache["k"], cache["v"], cache["block_tables"], pos,
-        interpret=rt.pallas_interpret)
+        q, cache["k"], cache["v"], cache["block_tables"], pos)
 
 
 # ---------------------------------------------------------------------------
@@ -1262,9 +1254,8 @@ def _paged_pool_specs(cache, ax):
 
 
 def _shard_paged(body, rt, in_specs, out_specs):
-    from jax.experimental.shard_map import shard_map
-    return shard_map(body, mesh=rt.mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=False)
+    return jax.shard_map(body, mesh=rt.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _tp_decode_paged(cfg, p, q, k, v, cache, pos, rt, ax):

@@ -75,8 +75,7 @@ def rglru_prefill(cfg: ModelConfig, p, x, state, rt=None):
 
     if rt is not None and rt.use_pallas and x.shape[1] > 1:
         from repro.kernels import ops
-        h, _ = ops.rglru_scan(a, bx, state["h"],
-                              interpret=rt.pallas_interpret)
+        h, _ = ops.rglru_scan(a, bx, state["h"])
     else:
         # fold in carried state: h_t = (prod a_1..t) h_0 + scan(b)
         def binop(e1, e2):

@@ -107,8 +107,7 @@ def rwkv_tmix(cfg: ModelConfig, p, x, state, rt=None):
     use_kernel = rt is not None and rt.use_pallas and S > 1
     if use_kernel:
         from repro.kernels import ops
-        y, S_fin = ops.rwkv6_wkv(r, k, v, w, p["u"], state["wkv"],
-                                 interpret=rt.pallas_interpret)
+        y, S_fin = ops.rwkv6_wkv(r, k, v, w, p["u"], state["wkv"])
     else:
         def step(Sm, inp):
             w_t, k_t, v_t, r_t = inp

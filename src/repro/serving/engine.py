@@ -28,6 +28,7 @@ import numpy as np
 from repro.config import ModelConfig
 from repro.core import HashEmbedder, Recycler
 from repro.core import quant
+from repro.core.blockpool import AdmissionRejected
 from repro.core.kvstore import to_host
 from repro.core.recycler import (grow_capacity, is_trimmable,
                                  shrink_capacity, trim_to_depth)
@@ -422,8 +423,9 @@ class BatchedEngine(Engine):
         ids = self.tok.encode(prompt)
         m = len(ids)
         if m + max_new > self.capacity:
-            raise ValueError(f"request needs {m + max_new} positions; pool "
-                             f"capacity is {self.capacity}")
+            raise AdmissionRejected(
+                f"request needs {m + max_new} positions; pool "
+                f"capacity is {self.capacity}")
 
         depth, hit, mode, sim = 0, False, "baseline", 0.0
         if use_recycling:

@@ -70,7 +70,8 @@ import numpy as np
 
 from repro.config import ModelConfig
 from repro.core import BlockAllocator, BlockPoolExhausted, BlockTrie
-from repro.core.blockpool import SENTINEL, PoolSaturated
+from repro.core.blockpool import (SENTINEL, AdmissionRejected,
+                                  PoolSaturated)
 from repro.core.faults import InjectedFault
 from repro.core.kvstore import to_host, tree_bytes
 from repro.core import quant as kvq
@@ -80,6 +81,7 @@ from repro.data.tokenizer import EOS
 from repro.models import (decode_step, draft_refine, draft_view,
                           init_cache, init_paged_pool, paged_block_bytes,
                           prefill_paged, prefill_paged_packed, verify_paged)
+from repro.runtime import Runtime, on_tpu
 from repro.serving import engine as engine_mod
 from repro.serving.engine import Engine, GenResult, _Slot
 from repro.serving.sampling import sample_batched, sample_logits
@@ -499,6 +501,8 @@ class PagedEngine(Engine):
                  preempt_policy: str = "least_progress",
                  overcommit: bool = False,
                  fault_plan=None, **kw):
+        # the paged kernels on a TPU, the jnp references elsewhere
+        kw.setdefault("rt", Runtime(use_pallas=on_tpu()))
         if kw.get("kv_quant"):
             # the int8 tier compresses its host tier by default, with a
             # residual deep enough that a promoted prefix can fill the
@@ -1062,10 +1066,7 @@ class PagedEngine(Engine):
         fn = {"chunked": self._chunk_fn,
               "packed": self._packed_fn}.get(self.prefill_mode,
                                              self._prefill_fn)
-        try:
-            return fn._cache_size()
-        except AttributeError:  # pragma: no cover - older jax
-            return -1
+        return fn._cache_size()
 
     # ------------------------------------------------------------------
     def _convert_dense_quant(self, cache):
@@ -1417,8 +1418,9 @@ class PagedEngine(Engine):
             ids = self.tok.encode(prompt)
             m = len(ids)
         if m + max_new > self.capacity:
-            raise ValueError(f"request needs {m + max_new} positions; pool "
-                             f"capacity is {self.capacity}")
+            raise AdmissionRejected(
+                f"request needs {m + max_new} positions; pool "
+                f"capacity is {self.capacity}")
         if self.prefill_mode in ("chunked", "packed"):
             return self._admit_chunked(slot, prompt, ids, m, max_new,
                                        use_recycling, admit, stop_at_eos,
@@ -1472,7 +1474,7 @@ class PagedEngine(Engine):
             if self.active_slots() or self._pending:
                 # in-flight rows will free blocks — transient, retry later
                 raise PoolSaturated(msg)
-            raise ValueError(msg)
+            raise AdmissionRejected(msg)
 
         for b in shared:                      # share the resident prefix
             self.allocator.ref(b)
@@ -1608,7 +1610,7 @@ class PagedEngine(Engine):
         machinery demotes a victim instead — how an undersized pool
         oversubscribes rather than rejecting.  Saturation that in-flight
         work will relieve raises ``PoolSaturated`` (scheduler keeps the
-        request queued); ``ValueError`` remains the permanent reject."""
+        request queued); ``AdmissionRejected`` is the permanent reject."""
         nb_total = _ceil_div(m + max_new, self.block)
         need = _ceil_div(m, self.block) if self.overcommit else nb_total
         owed = 0 if self.overcommit else sum(self._committed)
@@ -1621,7 +1623,7 @@ class PagedEngine(Engine):
                 f"in-flight reservations={owed})")
             if self.active_slots() or self._pending:
                 raise PoolSaturated(msg)
-            raise ValueError(msg)
+            raise AdmissionRejected(msg)
         self._committed[slot] = nb_total
         self._tables[slot] = SENTINEL
         self._row_blocks[slot] = []

@@ -38,7 +38,7 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Deque, Dict, List, Optional
 
-from repro.core.blockpool import PoolSaturated
+from repro.core.blockpool import AdmissionRejected, PoolSaturated
 from repro.serving.engine import BatchedEngine, Engine, GenResult
 
 
@@ -327,9 +327,9 @@ class ContinuousBatchingScheduler:
                 self._queue.appendleft(req)
                 self.stats["admissions_deferred"] += 1
                 break
-            except ValueError as e:
-                # reject THIS request (e.g. longer than the pool capacity)
-                # without dropping the rest of the queue or the slot
+            except AdmissionRejected as e:
+                # reject THIS request (it can never fit the pool) without
+                # dropping the rest of the queue or the slot
                 self._free.append(slot)
                 req.error = str(e)
                 req.outcome = RequestOutcome.ERRORED

@@ -211,6 +211,38 @@ def test_permanent_reject_is_errored(stack):
     assert req.error is not None
 
 
+@pytest.mark.parametrize("fault", ["over_capacity", "engine_error"])
+def test_only_capacity_rejects_are_errored(stack, monkeypatch, fault):
+    """The scheduler turns exactly the capacity rejection into ERRORED and
+    keeps serving; any other error raised inside ``admit_slot`` — even a
+    ValueError — propagates out of ``run`` instead of being recorded as
+    a finished request."""
+    cfg, params = stack
+    eng = PagedEngine(cfg, params, max_batch=2, capacity=128,
+                      max_new_tokens=4, block_size=8)
+    sched = ContinuousBatchingScheduler(eng)
+    if fault == "over_capacity":
+        bad = sched.submit("word " * 40)
+        good = sched.submit(PROMPTS[1])
+        sched.run()
+        assert bad.outcome == RequestOutcome.ERRORED
+        assert "capacity" in bad.error
+        assert good.outcome == RequestOutcome.OK
+        assert sched.stats["rejected"] == 1
+        return
+
+    def broken(prompt, **_):
+        raise ValueError("tokenizer table corrupt")
+
+    monkeypatch.setattr(eng.tok, "encode", broken)
+    req = sched.submit(PROMPTS[1])
+    with pytest.raises(ValueError, match="tokenizer table corrupt"):
+        sched.run()
+    assert req.outcome != RequestOutcome.ERRORED
+    assert sched.stats["rejected"] == 0
+    assert eng.free_slots() == [0, 1]       # the slot went back
+
+
 def test_victim_policy_least_progress(stack):
     """Under pressure the victim is the least-progress row (fewest
     emitted tokens), latest deadline breaking ties."""
