@@ -222,7 +222,7 @@ def main():
                       f"fallback steps")
         print("NOTE: per-request latency below spans the whole shared batch "
               "(queue wait included); batching trades it for throughput — "
-              "see benchmarks/continuous_batching.py for tokens/s")
+              "see perfbench/run.py for tokens/s on a TPU")
     else:
         for p in test_prompts:
             sched.submit(p, admit=True)      # recycled + admit for reuse

@@ -8,8 +8,8 @@ from __future__ import annotations
 
 import math
 import time
-from dataclasses import dataclass, field, asdict
-from typing import Dict, List, Optional
+from dataclasses import dataclass, asdict
+from typing import Dict, List
 
 import numpy as np
 
@@ -74,146 +74,6 @@ def summarize_runs(baseline: List[RunMetrics], recycled: List[RunMetrics],
             1 for k in keys if rec[k].prompt_similarity > 0.8),
         "latency_baseline_avg_s": _avg(base[k].latency_s for k in keys),
         "latency_recycled_avg_s": _avg(rec[k].latency_s for k in keys),
-    }
-
-
-def tpot_summary(results) -> Dict:
-    """TPOT / TTFT summary over GenResults (anything carrying
-    ``step_times_s`` / ``ttft_s``): p50/p95/mean time-per-output-token
-    plus mean and p95 time-to-first-token — the serving latency pair
-    (TTFT = admission cost, TPOT = decode cadence).  A speculative
-    round's burst is recorded as equal per-token shares of the round's
-    wall time, so accepted drafts show up as LOWER TPOT samples rather
-    than as missing ones.
-
-    Degenerate inputs are well-defined instead of raising or emitting
-    NaN (``json.dump`` writes NaN as invalid JSON): results whose
-    ``step_times_s`` / ``ttft_s`` is absent or None contribute no
-    samples; with no samples the corresponding fields are None and
-    ``tpot_samples`` is 0; with a single sample every percentile is
-    that sample."""
-    steps = [t for r in results
-             for t in (getattr(r, "step_times_s", None) or [])]
-    ttfts = [t for t in (getattr(r, "ttft_s", None) for r in results)
-             if t is not None and t > 0.0]
-
-    def pct(xs, q):
-        return float(np.percentile(xs, q)) if xs else None
-
-    return {
-        "tpot_p50_s": pct(steps, 50),
-        "tpot_p95_s": pct(steps, 95),
-        "tpot_mean_s": float(np.mean(steps)) if steps else None,
-        "tpot_samples": len(steps),
-        "ttft_mean_s": float(np.mean(ttfts)) if ttfts else None,
-        "ttft_p95_s": pct(ttfts, 95),
-    }
-
-
-def slo_summary(results, requests=None, *, ttft_slo_s: Optional[float] = None,
-                tpot_slo_s: Optional[float] = None) -> Dict:
-    """Serving-SLO summary: TTFT / TPOT / queue-delay percentiles plus the
-    fraction of requests meeting every GIVEN target.
-
-    ``results`` are GenResults (or anything carrying ``ttft_s`` /
-    ``step_times_s``); ``requests`` are scheduler ``Request`` objects
-    (anything carrying ``queue_delay_s``) for the admission-queue view —
-    pass the same objects the ContinuousBatchingScheduler returned.
-
-    SLO attainment is judged per REQUEST: a request attains when its TTFT
-    meets ``ttft_slo_s`` (if given) AND its p95 per-token step time meets
-    ``tpot_slo_s`` (if given).  With no targets given, or no measurable
-    requests, ``slo_attainment`` is None — same None-not-NaN convention
-    as ``tpot_summary`` (NaN is invalid JSON)."""
-    def pct(xs, q):
-        return float(np.percentile(xs, q)) if xs else None
-
-    ttfts = [t for t in (getattr(r, "ttft_s", None) for r in results)
-             if t is not None and t > 0.0]
-    steps = [t for r in results
-             for t in (getattr(r, "step_times_s", None) or [])]
-    delays = [d for d in (getattr(r, "queue_delay_s", None)
-                          for r in (requests or []))
-              if d is not None]
-
-    attained = None
-    samples = 0
-    if ttft_slo_s is not None or tpot_slo_s is not None:
-        ok = 0
-        for r in results:
-            ttft = getattr(r, "ttft_s", None)
-            rsteps = getattr(r, "step_times_s", None) or []
-            meets = True
-            measurable = False
-            if ttft_slo_s is not None and ttft is not None and ttft > 0.0:
-                measurable = True
-                meets = meets and ttft <= ttft_slo_s
-            if tpot_slo_s is not None and rsteps:
-                measurable = True
-                meets = meets and pct(rsteps, 95) <= tpot_slo_s
-            if measurable:
-                samples += 1
-                ok += int(meets)
-        attained = ok / samples if samples else None
-
-    out = {
-        "ttft_p50_s": pct(ttfts, 50),
-        "ttft_p95_s": pct(ttfts, 95),
-        "tpot_p50_s": pct(steps, 50),
-        "tpot_p95_s": pct(steps, 95),
-        "queue_delay_p50_s": pct(delays, 50),
-        "queue_delay_p95_s": pct(delays, 95),
-        "slo_attainment": attained,
-        "slo_samples": samples,
-    }
-    out.update(_pressure_summary(results, requests))
-    return out
-
-
-def _pressure_summary(results, requests) -> Dict:
-    """Overload-era additions to the SLO view: typed shed rates,
-    preemption rates, and deadline attainment, computed from scheduler
-    ``Request`` outcomes (``RequestOutcome``) and GenResult preemption
-    counters.  All rates are fractions of SUBMITTED requests, so a
-    server that sheds 30% cannot launder its p95 by only reporting the
-    requests it chose to serve.  None (not NaN) when no requests were
-    given — same JSON-safe convention as the rest of the summary."""
-    reqs = list(requests or [])
-    n = len(reqs)
-    outcomes: Dict[str, int] = {}
-    for r in reqs:
-        o = getattr(r, "outcome", None)
-        if o is not None:
-            outcomes[o] = outcomes.get(o, 0) + 1
-    shed = (outcomes.get("shed_queue_full", 0)
-            + outcomes.get("shed_deadline", 0))
-    preempted = [r for r in results
-                 if getattr(r, "preemptions", 0) > 0]
-    recomputed = sum(getattr(r, "tokens_recomputed", 0) for r in results)
-    # deadline attainment: of requests that CARRIED a deadline, how many
-    # produced their result before it (shed-on-deadline counts as missed;
-    # requests without deadlines are excluded, not counted as attained)
-    dl_total = dl_ok = 0
-    for r in reqs:
-        dl = getattr(r, "deadline_t", None)
-        if dl is None:
-            continue
-        dl_total += 1
-        ft = getattr(r, "first_token_t", None)
-        if (getattr(r, "outcome", None) == "ok"
-                and (ft is None or ft <= dl)):
-            dl_ok += 1
-    return {
-        "requests_submitted": n if requests is not None else None,
-        "outcome_counts": outcomes if requests is not None else None,
-        "shed_rate": (shed / n) if n else None,
-        "errored_rate": (outcomes.get("errored", 0) / n) if n else None,
-        "preempted_results": len(preempted),
-        "preemption_rate": (len(preempted) / len(results)
-                            if results else None),
-        "tokens_recomputed": int(recomputed),
-        "deadline_attainment": (dl_ok / dl_total) if dl_total else None,
-        "deadline_samples": dl_total,
     }
 
 

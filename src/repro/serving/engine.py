@@ -54,11 +54,6 @@ class GenResult:
     # per-request serving analogue, what the chunked-admission path exists
     # to shrink).  0.0 when the engine predates the measurement.
     ttft_s: float = 0.0
-    # per-decode-step wall times (TPOT samples): one entry per emitted
-    # token after the first.  A speculative round that emits a burst of
-    # k tokens records k entries of round_time / k — honest per-token
-    # latency, so accepted drafts show up as LOWER TPOT, not as gaps.
-    step_times_s: list = field(default_factory=list)
     # pressure-safe serving (paged engine): how often this request was
     # preempted + resumed, and how many positions its resumes had to
     # re-prefill — 0/0 on engines without preemption
@@ -173,17 +168,8 @@ class Engine:
         jax.block_until_ready(tok)
         ttft = time.perf_counter() - t0
         pos = m
-        step_times: List[float] = []
-        t_prev = time.perf_counter()
-        for k in range(max_new):
-            # int() forces the step's value: the wall time since t_prev
-            # covers exactly one decode step + sample (a TPOT sample);
-            # the first iteration is the prefill->token gap (TTFT), not
-            # a decode step, and is excluded
+        for _ in range(max_new):
             out_ids.append(int(tok[0, 0]))
-            if k:
-                step_times.append(time.perf_counter() - t_prev)
-            t_prev = time.perf_counter()
             if stop_at_eos and out_ids[-1] == EOS:
                 break
             logits, cache = self._decode_fn(self.params, tok, cache,
@@ -222,7 +208,6 @@ class Engine:
             mode=mode if use_recycling else "baseline",
             prompt_similarity=sim,
             ttft_s=ttft,
-            step_times_s=step_times,
         )
 
     # ------------------------------------------------------------------
@@ -256,7 +241,6 @@ class _Slot:
     t_first: float = 0.0         # when the first token was sampled (TTFT)
     temperature: float = 0.0     # 0 = greedy (the paper's do_sample=False)
     top_k: int = 0
-    step_times_s: list = field(default_factory=list)  # TPOT samples
     tenant: Optional[str] = None  # labels admitted host entries (quotas)
     # preemption bookkeeping (paged engine): tokens already emitted before
     # this slot was demoted + resumed, re-derived through warm admission.
@@ -484,16 +468,13 @@ class BatchedEngine(Engine):
         active = self.active_slots()
         if not active:
             return []
-        t_step = time.perf_counter()
         nxt, self._tokens, self.pool, self._pos = self._advance()
         toks = np.asarray(nxt)
-        dt_step = time.perf_counter() - t_step
         self.stats["batched_decode_steps"] += 1
         done: List[Tuple[int, GenResult]] = []
         for i in active:
             st = self._slots[i]
             st.emitted.append(int(toks[i]))
-            st.step_times_s.append(dt_step)
             if ((st.stop_at_eos and st.emitted[-1] == EOS)
                     or len(st.emitted) >= st.max_new):
                 done.append((i, self._result(
@@ -532,5 +513,4 @@ class BatchedEngine(Engine):
             mode=st.mode if st.use_recycling else "baseline",
             prompt_similarity=st.sim,
             ttft_s=max(st.t_first - st.t0, 0.0),
-            step_times_s=list(st.step_times_s),
         )

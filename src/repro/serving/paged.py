@@ -85,6 +85,7 @@ from repro.runtime import Runtime, on_tpu
 from repro.serving import engine as engine_mod
 from repro.serving.engine import Engine, GenResult, _Slot
 from repro.serving.sampling import sample_batched, sample_logits
+from repro.serving.trace import span
 
 
 def _ceil_div(a: int, b: int) -> int:
@@ -715,6 +716,8 @@ class PagedEngine(Engine):
             "tokens_grafted": 0,
             "preemptions": 0, "preempted_tokens_recomputed": 0,
             "step_rollbacks": 0, "preempt_errors": 0,
+            # blocking device -> host reads on the serving path (_wait)
+            "host_syncs": 0,
         })
 
     # ------------------------------------------------------------------
@@ -724,6 +727,12 @@ class PagedEngine(Engine):
         the prefill — so one staging layout serves both pool tiers."""
         return init_cache(self.cfg, 1, capacity, window=self.window,
                           dtype=jnp.dtype(self.cfg.dtype), kv_quant=False)
+
+    def _wait(self, what: str):
+        """The span around one blocking device -> host read, counted in
+        ``stats["host_syncs"]``; ``what`` names the read site."""
+        self.stats["host_syncs"] += 1
+        return span("engine.wait", what=what)
 
     def _host_layout_ok(self, cache) -> bool:
         """A host entry is promotable iff it materializes to the plain fp
@@ -776,8 +785,12 @@ class PagedEngine(Engine):
         codes (plus a dequantized fp residual tail), so the quantization
         the blocks received at their seal is the only one they ever get."""
         if not self.kv_quant:
-            return to_host(self._stage_fn(self.pool, chain_ids, depth, cap))
-        g = to_host(self._gather_q_fn(self.pool, chain_ids, depth, cap))
+            staged = self._stage_fn(self.pool, chain_ids, depth, cap)
+            with self._wait("harvest"):
+                return to_host(staged)
+        gathered = self._gather_q_fn(self.pool, chain_ids, depth, cap)
+        with self._wait("harvest"):
+            g = to_host(gathered)
         residual = self.recycler.compress_residual
         split = max(0, depth - residual)
         dt = jnp.dtype(self.cfg.dtype)
@@ -911,7 +924,6 @@ class PagedEngine(Engine):
         truncation + refcount release + exact ring restore).  Token-for-
         token identical to plain greedy steps — the drafts only decide
         how many of those steps one round buys."""
-        t_round = time.perf_counter()
         bs = self.block
         B, g = self.max_batch, self.gamma
         W = self.nbt + 2 * self.max_batch
@@ -967,7 +979,8 @@ class PagedEngine(Engine):
         dts = self._draft_fn(
             self.params, jnp.asarray(tok0), self.pool,
             jnp.asarray(pos_h), jnp.asarray(dtab), jnp.asarray(dbase))
-        draft = self._draft_tokens(np.asarray(dts))
+        with self._wait("draft"):
+            draft = self._draft_tokens(np.asarray(dts))
 
         vt = np.zeros((B, self.spec_cv), np.int32)
         for i in active:
@@ -976,8 +989,8 @@ class PagedEngine(Engine):
         tg, self.pool = self._verify_fn(
             self.params, jnp.asarray(vt), self.pool, snap,
             jnp.asarray(pos_h), jnp.asarray(act_h))
-        targets = np.asarray(tg)
-        dt_round = time.perf_counter() - t_round
+        with self._wait("verify"):
+            targets = np.asarray(tg)
 
         done: List[Tuple[int, GenResult]] = []
         roll_updates: List[Tuple[int, int, int]] = []
@@ -1007,10 +1020,6 @@ class PagedEngine(Engine):
                     finished = True
                     break
             self.stats["spec_emitted_tokens"] += n_emit
-            # honest per-token latency: the round emitted n_emit tokens
-            # in one burst — each records an equal share of the round
-            for _ in range(n_emit):
-                st.step_times_s.append(dt_round / n_emit)
             if finished:
                 done.append((i, self._result(st, row=i)))
                 self._release_row(i)
@@ -1274,24 +1283,26 @@ class PagedEngine(Engine):
         tokens through the ordinary warm admission machinery, which is
         what makes a preempted-then-resumed greedy run token-identical
         to an uninterrupted one."""
-        st = self._slots[row]
-        p = st.m + len(st.emitted) - 1
-        if st.use_recycling and p > 0:
-            cap = self._capacity(st.m + st.max_new)
-            chain = [b for b in self._tables[row]
-                     if b != SENTINEL][:_ceil_div(p, self.block)]
-            entry = self._harvest(jnp.asarray(chain, jnp.int32), p, cap)
-            if self.kv_quant:
-                self._overlay_ring_tail(entry, row, p)
-            ids_sealed = np.concatenate(
-                [st.ids, np.asarray(st.emitted[:-1], np.int32)])
-            self.recycler.admit(st.prompt, ids_sealed, entry, p, cap,
-                                tenant=st.tenant)
-        payload = self._resume_payload(st, st.emitted)
-        payload["slot"] = row
-        self._release_row(row)
-        self._events.append(("preempted", payload))
-        self.stats["preemptions"] += 1
+        with span("engine.preempt"):
+            st = self._slots[row]
+            p = st.m + len(st.emitted) - 1
+            if st.use_recycling and p > 0:
+                cap = self._capacity(st.m + st.max_new)
+                chain = [b for b in self._tables[row]
+                         if b != SENTINEL][:_ceil_div(p, self.block)]
+                entry = self._harvest(jnp.asarray(chain, jnp.int32), p,
+                                      cap)
+                if self.kv_quant:
+                    self._overlay_ring_tail(entry, row, p)
+                ids_sealed = np.concatenate(
+                    [st.ids, np.asarray(st.emitted[:-1], np.int32)])
+                self.recycler.admit(st.prompt, ids_sealed, entry, p, cap,
+                                    tenant=st.tenant)
+            payload = self._resume_payload(st, st.emitted)
+            payload["slot"] = row
+            self._release_row(row)
+            self._events.append(("preempted", payload))
+            self.stats["preemptions"] += 1
 
     def _overlay_ring_tail(self, entry, row: int, depth: int) -> None:
         """Overlay the row's LIVE fp ring values into a harvest entry's
@@ -1310,7 +1321,8 @@ class PagedEngine(Engine):
         lo = max(max(0, fb - R + 1) * bs, split)
         for seg, sub in entry.items():
             for name in ("k", "v"):
-                ring = np.asarray(self.pool[seg][name + "_tail"][:, row])
+                with self._wait("ring_tail"):
+                    ring = np.asarray(self.pool[seg][name + "_tail"][:, row])
                 tail = sub[name]["tail"]          # (L, 1, depth-split, H, D)
                 for q in range(lo, depth):
                     off = (q // bs % R) * bs + q % bs
@@ -1341,16 +1353,18 @@ class PagedEngine(Engine):
         even after preempting every eligible victim: demote THIS row
         (or, for a grafted row — which must never be demoted — error it
         out) so ``BlockPoolExhausted`` never escapes the step."""
-        st = self._slots[row]
-        if st.mode == "semantic_block":
-            self._events.append(("errored", {
-                "slot": row, "prompt": st.prompt, "tenant": st.tenant,
-                "error": "pool exhausted: grafted row cannot be demoted"}))
-            self.stats["preempt_errors"] += 1
-            self._release_row(row)
-        else:
-            self._preempt_row(row)
-        self._preempted_now.add(row)
+        with span("engine.preempt"):
+            st = self._slots[row]
+            if st.mode == "semantic_block":
+                self._events.append(("errored", {
+                    "slot": row, "prompt": st.prompt, "tenant": st.tenant,
+                    "error": "pool exhausted: grafted row cannot be "
+                             "demoted"}))
+                self.stats["preempt_errors"] += 1
+                self._release_row(row)
+            else:
+                self._preempt_row(row)
+            self._preempted_now.add(row)
 
     def device_kv_bytes_in_use(self) -> int:
         """Bytes of pool K/V actually referenced (live blocks, counted
@@ -1556,9 +1570,11 @@ class PagedEngine(Engine):
         self.stats["tokens_prefilled"] += m - depth
         self.stats["admissions"] += 1
 
+        with self._wait("first_token"):
+            first = int(tok0[0])
         st = _Slot(prompt, ids, m, max_new, use_recycling, admit,
                    stop_at_eos, depth, hit, mode, sim,
-                   emitted=[int(tok0[0])], t0=t0,
+                   emitted=[first], t0=t0,
                    t_first=time.perf_counter(),
                    temperature=temperature, top_k=top_k, tenant=tenant)
         st.deadline_t = deadline_t
@@ -1847,8 +1863,10 @@ class PagedEngine(Engine):
         lo, hi = plan.interior_lo, plan.interior_hi
         bids = [int(self._tables[slot][j]) for j in range(plan.b0, lo)]
         n = len(bids) * bs
-        got = to_host(self._stage_fn(self.pool,
-                                     jnp.asarray(bids, jnp.int32), n, n))
+        staged = self._stage_fn(self.pool, jnp.asarray(bids, jnp.int32),
+                                n, n)
+        with self._wait("graft_gate"):
+            got = to_host(staged)
         rels = []
         for seg, ref in adm.graft_ref.items():
             for name in ("k", "v"):
@@ -1904,46 +1922,49 @@ class PagedEngine(Engine):
         st = adm.st
         bs = self.block
         if not adm.started:
-            self._begin_admission(slot, adm)
-        c0 = adm.next_c0
-        # a grafted admission chunks per SEGMENT: [0, seg1_end) first,
-        # then — if the gate accepts — [graft_end, m), skipping the
-        # grafted interior entirely
-        seg_end = adm.segs[adm.seg_i][1] if adm.segs else st.m
-        remaining = seg_end - c0
-        C = next((s for s in self.chunk_shapes if s >= remaining),
-                 self.prefill_chunk)
-        n_valid = min(C, remaining)
-        for idx in range(c0 // bs, (c0 + n_valid - 1) // bs + 1):
-            if self._tables[slot][idx] == SENTINEL:
-                b = self._alloc_pressure(exclude_pending=(slot,))
-                self._tables[slot][idx] = b
-                self._committed[slot] -= 1
-        # rebuild rather than append: a graft installs interior blocks at
-        # HIGHER table indices than the segment being chunked, and the
-        # row_blocks invariant is table order
-        self._row_blocks[slot] = [int(x) for x in self._tables[slot]
-                                  if x != SENTINEL]
-        toks = np.zeros((1, C), np.int32)
-        toks[0, :n_valid] = st.ids[c0:c0 + n_valid]
-        logits, self.pool = self._chunk_fn(
-            self.params, jnp.asarray(toks), self.pool, jnp.int32(slot),
-            jnp.asarray(self._tables[slot]), jnp.int32(c0),
-            jnp.int32(adm.w_floor), jnp.int32(n_valid))
-        self.stats["prefill_chunks"] += 1
-        self.stats["prefill_dispatches"] += 1
-        # progressive L1 registration: blocks this chunk sealed become
-        # shareable immediately — a neighbor admitted this same step can
-        # compose its table from them at ITS first chunk.  ``reg_cap``
-        # stops the frontier at the graft: grafted interior and
-        # post-graft blocks are approximate under THIS prompt's keys and
-        # must never serve an exact-prefix lookup
-        reg_len = min(c0 + n_valid, adm.reg_cap)
-        if reg_len > 0:
-            for b in self.trie.register(st.ids, reg_len,
-                                        self._row_blocks[slot]):
-                self.allocator.ref(b)
-        adm.next_c0 = c0 + n_valid
+            with span("engine.tier_lookup"):
+                self._begin_admission(slot, adm)
+        with span("engine.chunk"):
+            c0 = adm.next_c0
+            # a grafted admission chunks per SEGMENT: [0, seg1_end)
+            # first, then — if the gate accepts — [graft_end, m),
+            # skipping the grafted interior entirely
+            seg_end = adm.segs[adm.seg_i][1] if adm.segs else st.m
+            remaining = seg_end - c0
+            C = next((s for s in self.chunk_shapes if s >= remaining),
+                     self.prefill_chunk)
+            n_valid = min(C, remaining)
+            for idx in range(c0 // bs, (c0 + n_valid - 1) // bs + 1):
+                if self._tables[slot][idx] == SENTINEL:
+                    b = self._alloc_pressure(exclude_pending=(slot,))
+                    self._tables[slot][idx] = b
+                    self._committed[slot] -= 1
+            # rebuild rather than append: a graft installs interior
+            # blocks at HIGHER table indices than the segment being
+            # chunked, and the row_blocks invariant is table order
+            self._row_blocks[slot] = [int(x) for x in self._tables[slot]
+                                      if x != SENTINEL]
+            toks = np.zeros((1, C), np.int32)
+            toks[0, :n_valid] = st.ids[c0:c0 + n_valid]
+            logits, self.pool = self._chunk_fn(
+                self.params, jnp.asarray(toks), self.pool,
+                jnp.int32(slot), jnp.asarray(self._tables[slot]),
+                jnp.int32(c0), jnp.int32(adm.w_floor), jnp.int32(n_valid))
+            self.stats["prefill_chunks"] += 1
+            self.stats["prefill_dispatches"] += 1
+            # progressive L1 registration: blocks this chunk sealed
+            # become shareable immediately — a neighbor admitted this
+            # same step can compose its table from them at ITS first
+            # chunk.  ``reg_cap`` stops the frontier at the graft:
+            # grafted interior and post-graft blocks are approximate
+            # under THIS prompt's keys and must never serve an
+            # exact-prefix lookup
+            reg_len = min(c0 + n_valid, adm.reg_cap)
+            if reg_len > 0:
+                for b in self.trie.register(st.ids, reg_len,
+                                            self._row_blocks[slot]):
+                    self.allocator.ref(b)
+            adm.next_c0 = c0 + n_valid
         if adm.segs and adm.seg_i == 0 and adm.next_c0 >= adm.gate_at:
             # segment 1 reached the graft: run the fidelity gate.  On
             # accept it advances next_c0 past the interior; on refusal it
@@ -1991,7 +2012,8 @@ class PagedEngine(Engine):
             self._pending_planned.add(slot)
             try:
                 if not adm.started:
-                    self._begin_admission(slot, adm)
+                    with span("engine.tier_lookup"):
+                        self._begin_admission(slot, adm)
                 c0 = adm.next_c0
                 remaining = st.m - c0
                 C = next((s for s in self.chunk_shapes if s >= remaining),
@@ -2052,31 +2074,34 @@ class PagedEngine(Engine):
         DEVICE block table (the first moment decode may write through it),
         arm the row for the batched decode loop, and account the
         admission."""
-        adm = self._pending.pop(slot)
-        st = adm.st
-        if st.temperature > 0.0:
-            self._step_rng, sub = jax.random.split(self._step_rng)
-            tok0 = sample_logits(logits, sub, temperature=st.temperature,
-                                 top_k=st.top_k)
-        else:
-            tok0 = engine_mod.greedy(logits)
-        st.emitted = [int(tok0[0])]
-        st.t_first = st.t_first or time.perf_counter()
-        self.stats["requests"] += 0 if st.resume_emitted else 1
-        if st.resume_emitted:
-            rec = st.m - st.depth
-            st.tokens_recomputed += rec
-            self.stats["preempted_tokens_recomputed"] += rec
-        self.stats["hits"] += int(st.hit)
-        self.stats["tokens_reused"] += st.depth
-        self.stats["tokens_prefilled"] += st.m - st.depth
-        self.stats["admissions"] += 1
-        self._temp[slot] = st.temperature
-        self._topk[slot] = st.top_k
-        self.pool, self._tokens, self._pos = self._setrow_fn(
-            self.pool, self._tokens, self._pos, slot,
-            jnp.asarray(self._tables[slot]), tok0, jnp.int32(st.m))
-        self._slots[slot] = st
+        with span("engine.first_token"):
+            adm = self._pending.pop(slot)
+            st = adm.st
+            if st.temperature > 0.0:
+                self._step_rng, sub = jax.random.split(self._step_rng)
+                tok0 = sample_logits(logits, sub,
+                                     temperature=st.temperature,
+                                     top_k=st.top_k)
+            else:
+                tok0 = engine_mod.greedy(logits)
+            with self._wait("first_token"):
+                st.emitted = [int(tok0[0])]
+            st.t_first = st.t_first or time.perf_counter()
+            self.stats["requests"] += 0 if st.resume_emitted else 1
+            if st.resume_emitted:
+                rec = st.m - st.depth
+                st.tokens_recomputed += rec
+                self.stats["preempted_tokens_recomputed"] += rec
+            self.stats["hits"] += int(st.hit)
+            self.stats["tokens_reused"] += st.depth
+            self.stats["tokens_prefilled"] += st.m - st.depth
+            self.stats["admissions"] += 1
+            self._temp[slot] = st.temperature
+            self._topk[slot] = st.top_k
+            self.pool, self._tokens, self._pos = self._setrow_fn(
+                self.pool, self._tokens, self._pos, slot,
+                jnp.asarray(self._tables[slot]), tok0, jnp.int32(st.m))
+            self._slots[slot] = st
 
     # ------------------------------------------------------------------
     def _apply_table_updates(self,
@@ -2179,33 +2204,37 @@ class PagedEngine(Engine):
         self._preempted_now = set()
         if self.prefill_mode == "packed":
             # ALL pending admissions advance in ONE ragged packed dispatch
-            self._admission_step_packed()
+            with span("engine.packed_admission"):
+                self._admission_step_packed()
         else:
             for slot in sorted(self._pending):
                 if slot not in self._pending:
                     continue      # cancelled by a neighbor's pressure
-                try:
-                    self._admission_chunk(slot)
-                except (BlockPoolExhausted, InjectedFault):
-                    # contained: roll this admission back and requeue it
-                    if slot in self._pending:
-                        stc = self._cancel_admission(slot)
-                        payload = self._resume_payload(stc, [])
-                        payload["slot"] = slot
-                        self._events.append(("preempted", payload))
-                        self.stats["preemptions"] += 1
-                        self.stats["step_rollbacks"] += 1
-                        self._preempted_now.add(slot)
+                with span("engine.admission", row=slot):
+                    try:
+                        self._admission_chunk(slot)
+                    except (BlockPoolExhausted, InjectedFault):
+                        # contained: roll this admission back and
+                        # requeue it
+                        if slot in self._pending:
+                            stc = self._cancel_admission(slot)
+                            payload = self._resume_payload(stc, [])
+                            payload["slot"] = slot
+                            self._events.append(("preempted", payload))
+                            self.stats["preemptions"] += 1
+                            self.stats["step_rollbacks"] += 1
+                            self._preempted_now.add(slot)
         done: List[Tuple[int, GenResult]] = []
-        for i in self.active_slots():
-            st = self._slots[i]
-            # a row whose admission just completed may already be done
-            # (EOS at its first token, or a 1-token budget)
-            if len(st.emitted) == 1 and (
-                    (st.stop_at_eos and st.emitted[0] == EOS)
-                    or st.max_new == 1):
-                done.append((i, self._result(st, row=i)))
-                self._release_row(i)
+        with span("engine.emit"):
+            for i in self.active_slots():
+                st = self._slots[i]
+                # a row whose admission just completed may already be
+                # done (EOS at its first token, or a 1-token budget)
+                if len(st.emitted) == 1 and (
+                        (st.stop_at_eos and st.emitted[0] == EOS)
+                        or st.max_new == 1):
+                    done.append((i, self._result(st, row=i)))
+                    self._release_row(i)
         active = self.active_slots()
         if not active:
             return done
@@ -2213,11 +2242,47 @@ class PagedEngine(Engine):
                           and "alloc" in self.fault_plan.sites)
         if self.speculative and not spec_faultable:
             if self._spec_ready(active):
-                done.extend(self._spec_round(active))
+                with span("engine.spec_round"):
+                    done.extend(self._spec_round(active))
                 return done
             # sampled rows in the batch, bundle past capacity, or blocks
             # unobtainable: fall back to the plain step for this round
             self.stats["spec_fallback_steps"] += 1
+        with span("engine.table_update"):
+            active = self._grow_tables(active)
+        if not active:
+            return done
+
+        with span("engine.decode"):
+            if np.any(self._temp > 0.0):
+                self._step_rng, sub = jax.random.split(self._step_rng)
+                self.stats["sampled_steps"] += 1
+                nxt, self._tokens, self.pool, self._pos = \
+                    self._pstep_sampled_fn(
+                        self.params, self._tokens, self.pool, self._pos,
+                        jnp.asarray(self._temp), jnp.asarray(self._topk),
+                        sub, max(int(self._topk.max()), 1))
+            else:
+                nxt, self._tokens, self.pool, self._pos = self._pstep_fn(
+                    self.params, self._tokens, self.pool, self._pos)
+            with self._wait("decode"):
+                toks = np.asarray(nxt)
+        self.stats["batched_decode_steps"] += 1
+        with span("engine.emit"):
+            for i in active:
+                st = self._slots[i]
+                st.emitted.append(int(toks[i]))
+                if ((st.stop_at_eos and st.emitted[-1] == EOS)
+                        or len(st.emitted) >= st.max_new):
+                    done.append((i, self._result(st, row=i)))
+                    self._release_row(i)
+        return done
+
+    def _grow_tables(self, active: List[int]) -> List[int]:
+        """Give every row whose next write crosses into an unallocated
+        table entry a fresh block (preempting under pressure), reserve
+        the next block of rows near their boundary, and apply all the
+        table updates in one dispatch.  Returns the rows still active."""
         bs = self.block
         updates: List[Tuple[int, int, int]] = []
         for i in active:
@@ -2259,32 +2324,7 @@ class PagedEngine(Engine):
             active = [i for i in active if i not in self._preempted_now]
         if updates:
             self._apply_table_updates(updates)
-        if not active:
-            return done
-
-        t_step = time.perf_counter()
-        if np.any(self._temp > 0.0):
-            self._step_rng, sub = jax.random.split(self._step_rng)
-            self.stats["sampled_steps"] += 1
-            nxt, self._tokens, self.pool, self._pos = self._pstep_sampled_fn(
-                self.params, self._tokens, self.pool, self._pos,
-                jnp.asarray(self._temp), jnp.asarray(self._topk), sub,
-                max(int(self._topk.max()), 1))
-        else:
-            nxt, self._tokens, self.pool, self._pos = self._pstep_fn(
-                self.params, self._tokens, self.pool, self._pos)
-        toks = np.asarray(nxt)
-        dt_step = time.perf_counter() - t_step
-        self.stats["batched_decode_steps"] += 1
-        for i in active:
-            st = self._slots[i]
-            st.emitted.append(int(toks[i]))
-            st.step_times_s.append(dt_step)
-            if ((st.stop_at_eos and st.emitted[-1] == EOS)
-                    or len(st.emitted) >= st.max_new):
-                done.append((i, self._result(st, row=i)))
-                self._release_row(i)
-        return done
+        return active
 
     # ------------------------------------------------------------------
     def _release_row(self, row: int) -> None:
@@ -2340,7 +2380,6 @@ class PagedEngine(Engine):
             mode=st.mode if st.use_recycling else "baseline",
             prompt_similarity=st.sim,
             ttft_s=max(st.t_first - st.t0, 0.0),
-            step_times_s=list(st.step_times_s),
             preemptions=st.preemptions,
             tokens_recomputed=st.tokens_recomputed,
         )

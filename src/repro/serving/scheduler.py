@@ -40,6 +40,7 @@ from typing import Deque, Dict, List, Optional
 
 from repro.core.blockpool import AdmissionRejected, PoolSaturated
 from repro.serving.engine import BatchedEngine, Engine, GenResult
+from repro.serving.trace import install_gc_spans, span
 
 
 class RequestOutcome:
@@ -68,10 +69,9 @@ class Request:
     # HostKVStore; the scheduler's quota check reads the same accounting)
     tenant: Optional[str] = None
     submitted_at: float = field(default_factory=time.perf_counter)
-    # SLO clock: enqueue_t stamps at submit (== submitted_at, kept under
-    # both names for back-compat), admit_t when a slot is taken,
-    # first_token_t when the first token lands.  core.metrics.slo_summary
-    # consumes these.
+    # request clock: enqueue_t stamps at submit (== submitted_at, kept
+    # under both names for back-compat), admit_t when a slot is taken,
+    # first_token_t when the first token lands.
     enqueue_t: float = field(default_factory=time.perf_counter)
     admit_t: Optional[float] = None
     first_token_t: Optional[float] = None
@@ -160,6 +160,7 @@ class ContinuousBatchingScheduler:
                  tenant_queue_limits: Optional[Dict[str, int]] = None,
                  max_requeues: int = 32):
         self.engine = engine
+        install_gc_spans()
         # at most this many single-row prefills per step before decoding;
         # None = fill every free slot (prefill-heavy but maximal occupancy)
         if max_admissions_per_step is not None and max_admissions_per_step < 1:
@@ -393,11 +394,22 @@ class ContinuousBatchingScheduler:
         every in-flight request one token.  Returns the requests that
         completed this step (including admission-time completions:
         rejections, sheds and instant finishes)."""
-        finished: List[Request] = list(self._shed_expired())
-        finished.extend(self._admit())
-        decoded = bool(self.in_flight)
-        self.stats["occupancy_sum"] += len(self.in_flight)
-        for slot, result in self.engine.decode_batch():
+        with span("sched.step"):
+            with span("sched.admit"):
+                finished: List[Request] = list(self._shed_expired())
+                finished.extend(self._admit())
+            decoded = bool(self.in_flight)
+            self.stats["occupancy_sum"] += len(self.in_flight)
+            results = self.engine.decode_batch()
+            with span("sched.finish"):
+                self._finish(results, finished)
+            self.stats["decode_steps"] += int(decoded)
+        return finished
+
+    def _finish(self, results, finished: List[Request]) -> None:
+        """Hand the rows the engine finished back to their requests and
+        free their slots, then apply the engine's lifecycle events."""
+        for slot, result in results:
             req = self.in_flight.pop(slot)
             req.result = result
             req.outcome = RequestOutcome.OK
@@ -414,8 +426,6 @@ class ContinuousBatchingScheduler:
                 self.stats["slot_reuses"] += 1
             self._free.append(slot)
         self._drain_engine_events(finished)
-        self.stats["decode_steps"] += int(decoded)
-        return finished
 
     def run(self) -> List[Request]:
         while self._queue or self.in_flight:

@@ -334,19 +334,3 @@ def test_genresult_carries_preemption_counters(stack):
     assert (sum(r.result.tokens_recomputed for r in reqs)
             == eng.stats["preempted_tokens_recomputed"])
 
-
-def test_slo_summary_reports_pressure(stack):
-    from repro.core.metrics import slo_summary
-    cfg, params = stack
-    eng = PagedEngine(cfg, params, max_batch=2, capacity=128,
-                      max_new_tokens=4, block_size=8)
-    sched = ContinuousBatchingScheduler(eng, queue_limit=2)
-    reqs = [sched.submit(p) for p in PROMPTS]
-    sched.run()
-    results = [r.result for r in reqs if r.result is not None]
-    s = slo_summary(results, reqs)
-    assert s["requests_submitted"] == 3
-    assert s["outcome_counts"].get("shed_queue_full") == 1
-    assert s["shed_rate"] == pytest.approx(1 / 3)
-    assert s["tokens_recomputed"] == 0
-    assert s["preemption_rate"] == 0.0

@@ -5,8 +5,8 @@ fp and int8 pools, chunked and staged prefill, and early EOS; rollback is
 exact for ARBITRARY draft tokens (a hypothesis property substitutes
 random drafts and the output still cannot drift, with allocator/table/
 ring invariants holding after every step); the batched verify kernel
-matches the jnp reference; per-token TPOT samples land on GenResult; and
-``sample_batched`` short-circuits concrete all-greedy batches.
+matches the jnp reference; and ``sample_batched`` short-circuits concrete
+all-greedy batches.
 
 Plain greedy decode (``speculative=False``) is the reference baseline
 throughout — the same diff-the-outputs discipline the chunked-prefill
@@ -156,26 +156,8 @@ def test_spec_pallas_engine_equivalence(stack):
 
 
 # ---------------------------------------------------------------------------
-# TPOT satellites: per-step decode timing + the all-greedy fast path
+# the all-greedy fast path
 # ---------------------------------------------------------------------------
-def test_step_times_recorded_plain_and_spec(stack):
-    """Every decode-produced token carries a TPOT sample (the admission
-    token is TTFT, not TPOT); a speculative burst records equal shares
-    of its round, so totals stay per-token comparable."""
-    from repro.core.metrics import tpot_summary
-    for spec in (False, True):
-        eng = _paged(stack, spec=spec)
-        reqs = _run(eng, [p for p, _ in REQUESTS])
-        results = [r.result for r in reqs]
-        for r in results:
-            assert len(r.step_times_s) == r.gen_tokens - 1
-            assert all(t > 0.0 for t in r.step_times_s)
-        s = tpot_summary(results)
-        assert s["tpot_samples"] == sum(r.gen_tokens - 1 for r in results)
-        assert 0.0 < s["tpot_p50_s"] <= s["tpot_p95_s"]
-        assert s["ttft_mean_s"] > 0.0
-
-
 def test_sample_batched_all_greedy_fast_path():
     """A concrete all-zero temperature vector short-circuits to argmax —
     rng-independent — while any hot row still samples; the Tracer guard
