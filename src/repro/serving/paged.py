@@ -718,6 +718,10 @@ class PagedEngine(Engine):
             "step_rollbacks": 0, "preempt_errors": 0,
             # blocking device -> host reads on the serving path (_wait)
             "host_syncs": 0,
+            # decode dispatches: table entries the paged decode kernel
+            # walks for the decoding rows (through each row's last valid
+            # page), and the entries their tables hold
+            "decode_pages_walked": 0, "decode_table_pages": 0,
         })
 
     # ------------------------------------------------------------------
@@ -2253,6 +2257,11 @@ class PagedEngine(Engine):
         if not active:
             return done
 
+        bs, nbt = self.block, self.nbt
+        self.stats["decode_pages_walked"] += sum(
+            min((self._slots[i].m + len(self._slots[i].emitted) - 1) // bs,
+                nbt - 1) + 1 for i in active)
+        self.stats["decode_table_pages"] += len(active) * nbt
         with span("engine.decode"):
             if np.any(self._temp > 0.0):
                 self._step_rng, sub = jax.random.split(self._step_rng)

@@ -560,20 +560,87 @@ def test_paged_quant_pallas_engine_equivalence(stack):
 # ---------------------------------------------------------------------------
 # kernel: block-table gather matches the reference gather
 # ---------------------------------------------------------------------------
-def test_paged_kernel_matches_reference():
-    from repro.kernels import ops
+KBS, KNBT, KNB = 8, 6, 40            # page size, table width, pool blocks
+KIDLE = KNBT * KBS + 5               # an idle row counts past its table
+
+
+@pytest.mark.parametrize("pages", [None, 4], ids=["rule", "groups_of_4"])
+@pytest.mark.parametrize("hkv,group", [(2, 1), (2, 2), (1, 4)],
+                         ids=["mha", "gqa", "mqa"])
+@pytest.mark.parametrize("pos", [
+    [0], [KBS - 1], [KBS], [2 * KBS + 3], [KNBT * KBS - 1], [KIDLE],
+    [0, KNBT * KBS - 3, 9, 4 * KBS + 4, 2 * KBS + 3, KIDLE],
+], ids=["pos0", "page_end", "page_start", "mid_page", "table_end",
+        "idle_past_table", "ragged"])
+def test_paged_kernel_matches_reference(pos, hkv, group, pages,
+                                       monkeypatch):
+    """The row-walk decode kernel == the jnp reference gather: MHA, GQA
+    and one kv head (two heads share a slab row, one fills it), positions
+    at page edges and the table's end, an idle row whose ``pos`` runs
+    past a sentinel-only table, rows of very different lengths in one
+    batch, and groups of 4 pages (which do not divide the table) with
+    walks that end part-way through a group (``None``: the kernel's own
+    page rule).  The kernel is called unjitted, so that each case lowers
+    with its own page rule."""
+    from repro.kernels import decode_attention
     from repro.models.attention import attend_paged
+    if pages is not None:
+        monkeypatch.setattr(decode_attention, "_group_pages",
+                            lambda *a: pages)
     rng = np.random.default_rng(3)
-    B, NB, bs, H, hkv, dh = 3, 12, 8, 4, 2, 16
+    B, dh = len(pos), 16
+    H = hkv * group
     q = jnp.asarray(rng.normal(size=(B, 1, H, dh)), jnp.float32)
-    kp = jnp.asarray(rng.normal(size=(NB, bs, hkv, dh)), jnp.float32)
-    vp = jnp.asarray(rng.normal(size=(NB, bs, hkv, dh)), jnp.float32)
-    tables = jnp.asarray([[3, 5, 7, 0], [1, 2, 0, 0], [9, 8, 6, 4]],
-                         jnp.int32)
-    pos = jnp.asarray([25, 12, 31], jnp.int32)
-    out = ops.paged_decode_attention(q, kp, vp, tables, pos, interpret=True)
+    kp = jnp.asarray(rng.normal(size=(KNB, KBS, hkv, dh)), jnp.float32)
+    vp = jnp.asarray(rng.normal(size=(KNB, KBS, hkv, dh)), jnp.float32)
+    perm = rng.permutation(np.arange(1, KNB))
+    tables = np.zeros((B, KNBT), np.int32)
+    for b, p in enumerate(pos):
+        if p < KNBT * KBS:              # else idle: sentinel-only table
+            n = p // KBS + 1
+            tables[b, :n], perm = perm[:n], perm[n:]
+    tables, pos = jnp.asarray(tables), jnp.asarray(pos, jnp.int32)
+    out = decode_attention.paged_decode_attention(q, kp, vp, tables, pos,
+                                                  interpret=True)
     ref = attend_paged(q, {"k": kp, "v": vp, "block_tables": tables}, pos)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-5)
+
+
+def test_paged_pallas_engine_equivalence(stack):
+    """The Pallas fp decode path produces the same greedy tokens as the
+    jnp reference path on a real engine workload."""
+    from repro.runtime import Runtime
+    cfg, params = stack
+    outs = []
+    for rt in (Runtime(), Runtime(use_pallas=True)):
+        eng = PagedEngine(cfg, params, max_batch=2, capacity=128,
+                          max_new_tokens=5, block_size=8,
+                          enable_partial=True, rt=rt)
+        eng.precache(CACHED[:1])
+        reqs = _run_workload(eng, REQUESTS[:2])
+        outs.append([r.result.text for r in reqs])
+    assert outs[0] == outs[1]
+
+
+def test_decode_pages_walked_counter(stack):
+    """Each decode dispatch adds, for every decoding row, its table
+    entries through the last valid page, and the width of its table."""
+    cfg, params = stack
+    eng = PagedEngine(cfg, params, max_batch=3, capacity=128,
+                      max_new_tokens=12, block_size=8, enable_partial=True)
+    eng.precache(CACHED)
+    reqs = _run_workload(eng)
+    walked = table = 0
+    for r in reqs:
+        m, n = r.result.prompt_tokens, r.result.gen_tokens
+        # the first token comes from admission; decode step k writes and
+        # attends through position m + k - 1
+        walked += sum(min(p // 8, eng.nbt - 1) + 1
+                      for p in range(m, m + n - 1))
+        table += (n - 1) * eng.nbt
+    assert table > 0 and walked < table
+    assert eng.stats["decode_pages_walked"] == walked
+    assert eng.stats["decode_table_pages"] == table
 
 
 def test_paged_gather_equals_row_alone():

@@ -5,7 +5,9 @@ the chip's compiler refuses — a block shape that is neither tile-aligned
 nor the whole array dim, a select between boolean vectors — passes there
 and fails only on the chip.  These cases compile each kernel ahead of
 time for a *described* v5e chip at the serving widths (bf16,
-dialogpt-medium's 16 KV heads of 64 dims, 16-token pages); nothing runs.
+dialogpt-medium's 16 KV heads of 64 dims, 16-token pages; the decode
+kernel also at dialogpt-large's 20 heads, its 1,601-page pool and 24
+rows); nothing runs.
 
 The topology is described inside a fixture, never while a module is
 imported: only one process may load the TPU library, and under
@@ -28,6 +30,7 @@ NBT = 64              # table width: 1024-position rows
 B = 8                 # decode / verify rows
 C = 128               # prefill chunk
 R = 2                 # fp ring-tail blocks of the int8 pool
+HKV_L, NB_L, B_L = 20, 1601, 24   # dialogpt-large: heads, pool, rows
 
 
 @pytest.fixture(scope="module")
@@ -77,6 +80,11 @@ def _cases(s):
     return {
         "decode": (ops.paged_decode_attention,
                    [s((B, 1, H, D), bf), pool, pool, tables, rows]),
+        "decode_large": (ops.paged_decode_attention,
+                         [s((B_L, 1, HKV_L, D), bf),
+                          s((NB_L, BS, HKV_L, D), bf),
+                          s((NB_L, BS, HKV_L, D), bf),
+                          s((B_L, NBT), i32), s((B_L,), i32)]),
         "decode_int8": (ops.paged_decode_attention_quant,
                         [s((B, 1, H, D), bf), pool8, pool8, scale, scale,
                          tails, tails, tables, rows]),
@@ -100,7 +108,7 @@ def _cases(s):
     }
 
 
-KERNELS = ["decode", "decode_int8", "prefill", "prefill_int8", "packed",
+KERNELS = ["decode", "decode_large", "decode_int8", "prefill", "prefill_int8", "packed",
            "packed_int8", "verify", "verify_int8"]
 
 
